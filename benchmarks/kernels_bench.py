@@ -17,7 +17,9 @@ import numpy as np
 from repro.core import attention as A
 from repro.core import hamming
 from repro.kernels import ops
-from repro.launch.roofline import HBM_BW, PEAK_FLOPS
+from repro.launch.roofline import MODELED_KIND, chip_peaks
+
+HBM_BW = chip_peaks(MODELED_KIND).hbm_bw
 
 
 def _time(f, iters=5):

@@ -12,13 +12,15 @@ import time
 import traceback
 
 
-def main() -> None:
+def main() -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--fast", action="store_true")
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig1_runtime, fig3_topn, fig4_softmax,
                             fig5_quality, kernels_bench, roofline,
                             serve_bench, table1_glue, table2_imagenet,
@@ -47,6 +49,7 @@ def main() -> None:
         suites = [s for s in suites if s[0] in keep]
 
     csv_lines: list[str] = []
+    failed: list[str] = []
     for name, fn, kw in suites:
         print(f"\n===== {name} =====", flush=True)
         t0 = time.perf_counter()
@@ -55,12 +58,16 @@ def main() -> None:
         except Exception:
             traceback.print_exc()
             csv_lines.append(f"{name},0.0,ERROR")
+            failed.append(name)
         print(f"[{name}: {time.perf_counter() - t0:.0f}s]", flush=True)
 
     print("\n===== CSV (name,us_per_call,derived) =====")
     for line in csv_lines:
         print(line)
+    if failed:
+        print(f"suites that raised: {', '.join(failed)}", file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    raise SystemExit(main())

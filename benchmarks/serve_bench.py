@@ -91,6 +91,7 @@ import numpy as np
 from benchmarks.common import (causal_cfg, latency_samples, percentiles_ms,
                                preemption_attribution, scaling_efficiency,
                                slo_attainment)
+from repro.launch.roofline import MODELED_KIND, chip_peaks
 from repro.models import model as M
 from repro.serve import AsyncEngine, Engine, ServeConfig, Telemetry
 
@@ -309,12 +310,6 @@ def run(print_fn=print, slot_counts=(1, 2, 4), n_req: int = 4,
     return csv
 
 
-# nominal per-device HBM bandwidth for the bandwidth-bound decode model
-# in _mesh_case (forced host devices share one CPU, so wall-clock cannot
-# show real scaling; the model is exact arithmetic over measured traffic)
-NOMINAL_HBM_BW = 800e9
-
-
 def _mesh_case(print_fn, params, cfg, *, slots: int, n_req: int,
                page_size: int, mesh_model: int) -> list[str]:
     """Tensor-parallel scaling sweep: the same paged binary workload at
@@ -329,13 +324,15 @@ def _mesh_case(print_fn, params, cfg, *, slots: int, n_req: int,
     * each device holds exactly 1/N of the KV-pool bytes (kv-head
       sharding, divisibility validated);
     * modeled bandwidth-bound decode throughput — generated tokens over
-      (per-device traffic / NOMINAL_HBM_BW) — increases monotonically
-      with N, with scaling_efficiency reported per size.
+      (per-device traffic / the modeled chip's HBM bandwidth) — increases
+      monotonically with N, with scaling_efficiency reported per size.
 
     Wall-clock tok/s is reported but NOT asserted: forced host devices
-    all live on one CPU.
+    all live on one CPU, so the throughput model is exact arithmetic over
+    measured traffic at the modeled chip's published HBM bandwidth.
     """
     from repro.launch.mesh import make_host_mesh
+    hbm_bw = chip_peaks(MODELED_KIND).hbm_bw
     sweep = [m for m in (1, 2, 4, 8) if m <= mesh_model]
     if mesh_model not in sweep:
         sweep.append(mesh_model)
@@ -369,7 +366,7 @@ def _mesh_case(print_fn, params, cfg, *, slots: int, n_req: int,
         assert per_b * m == total_b, (
             f"m={m}: per-device pool bytes {per_b} x {m} != {total_b} — "
             f"kv-head sharding is not an exact 1/N split")
-        modeled = ngen / ((traffic / m) / NOMINAL_HBM_BW)
+        modeled = ngen / ((traffic / m) / hbm_bw)
         if base_tokens is None:
             base_tokens, base_traffic = tokens, traffic
             base_total, base_modeled = total_b, modeled

@@ -69,7 +69,7 @@ def run(print_fn=print) -> list[str]:
              f"{100 * byte_red:.1f}%  (paper: area 79%, power 87%)")
 
     # wall-clock cross-check of the fused decode kernel vs a dense f32
-    # reference (CPU interpret mode: correctness-grade, not perf-grade)
+    # reference (interpret mode on the CPU: correctness-grade only)
     rng = np.random.default_rng(0)
     q = jnp.asarray(rng.normal(size=(1, 1, DH)).astype(np.float32))
     k = jnp.asarray(rng.normal(size=(1, 1, CTX, DH)).astype(np.float32))
@@ -78,7 +78,7 @@ def run(print_fn=print) -> list[str]:
     lengths = jnp.asarray([CTX], jnp.int32)
     f = lambda: kops.decode_attention(qb, kb, v, d=DH, nsel=N_TOP,
                                       scale=DH ** -0.5, lengths=lengths,
-                                      block_t=64, interpret=True)
+                                      block_t=64)
     f()  # compile
     t0 = time.perf_counter()
     for _ in range(5):

@@ -5,7 +5,8 @@ cache, exact top-N via the histogram threshold (DESIGN.md §3), and the
 threshold-masked softmax·V accumulation — all in one kernel, streaming the
 K/V cache through VMEM in two passes:
 
-  pass 0: scores -> score-level histogram (d+1 int32 bins per query row)
+  pass 0: scores -> cumulative score-level histogram (per query row, the
+          count of keys at or above each of the d+1 levels)
           -> exact top-N threshold at the last block
   pass 1: scores recomputed (cheap: XOR+popcount), mask = score >= threshold,
           stable exp accumulation of numerator [G, Dv] and denominator [G]
@@ -38,23 +39,38 @@ def _scores(q: Array, k: Array, d: int) -> Array:
     return d - 2 * ham
 
 
-def _threshold(hist: Array, nsel: Array, d: int) -> Array:
-    """Exact top-N threshold score per row from the level histogram.
+def _at_or_above(s: Array, valid: Array, d: int) -> Array:
+    """Per-row counts of valid scores at or above each level.
 
-    hist: [G, d+1] counts; returns [G, 1] int32 threshold scores such that
-    keeping score >= t keeps >= min(nsel, total) entries (ties included).
+    s, valid: [R, bt]; returns [R, d+1] int32 with column l counting the
+    valid keys whose level (s + d) / 2 is >= l. Counts add across blocks,
+    and the threshold reads them directly, so no reversed scan is needed.
+    Builds an [R, bt, d+1] compare: for small R * bt (decode blocks).
     """
-    cc = jnp.cumsum(hist[:, ::-1], axis=-1)[:, ::-1]  # count(level >= l)
+    lv = jnp.where(valid, (s + d) >> 1, -1)                  # [R, bt]
+    ge = lv[:, :, None] >= jax.lax.broadcasted_iota(jnp.int32,
+                                                    (1, 1, d + 1), 2)
+    return jnp.sum(ge.astype(jnp.int32), axis=1)
+
+
+def _threshold(cc: Array, nsel: Array, d: int) -> Array:
+    """Exact top-N threshold score per row from the at-or-above counts.
+
+    cc: [G, d+1] counts (column l = count(level >= l)); returns [G, 1]
+    int32 threshold scores such that keeping score >= t keeps
+    >= min(nsel, total) entries (ties included).
+    """
     total = cc[:, :1]
     n_eff = jnp.minimum(nsel.astype(jnp.int32), total)
-    levels = jax.lax.broadcasted_iota(jnp.int32, hist.shape, 1)
+    levels = jax.lax.broadcasted_iota(jnp.int32, cc.shape, 1)
     idx = jnp.max(jnp.where(cc >= n_eff, levels, -1), axis=-1, keepdims=True)
     idx = jnp.maximum(idx, 0)
     return 2 * idx - d
 
 
 def _decode_kernel(len_ref, nsel_ref, scale_ref, q_ref, k_ref, v_ref, o_ref,
-                   hist_ref, thr_ref, num_ref, den_ref, blkmax_ref, *,
+                   hist_ref, thr_ref, num_ref, den_ref, blkmax_ref,
+                   thrmin_ref, *,
                    d: int, block_t: int, block_skip: bool):
     bh = pl.program_id(0)
     ph = pl.program_id(1)
@@ -76,27 +92,26 @@ def _decode_kernel(len_ref, nsel_ref, scale_ref, q_ref, k_ref, v_ref, o_ref,
     @pl.when(ph == 0)
     def _accum_hist():
         s, valid = scores_valid()
-        levels = (s + d) // 2                                    # [G, bt]
-        onehot = (levels[:, :, None] ==
-                  jax.lax.broadcasted_iota(jnp.int32, (1, 1, d + 1), 2))
-        onehot = jnp.logical_and(onehot, valid[:, :, None])
-        hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1)
+        hist_ref[...] += _at_or_above(s, valid, d)
         if block_skip:
             # per-block max score across all G rows: pass 2 skips blocks
             # whose best score misses every row's threshold — top-N then
             # saves actual V-read BYTES, not just flops (beyond-paper;
             # EXPERIMENTS.md §Perf). At N/T = 1-12% most blocks skip.
-            blkmax_ref[i, 0] = jnp.max(jnp.where(valid, s, -d - 2))
+            blkmax_ref[i] = jnp.max(jnp.where(valid, s, -d - 2))
 
     @pl.when((ph == 0) & (i == nb - 1))
     def _finalize_threshold():
-        thr_ref[...] = _threshold(hist_ref[...], nsel_ref[0], d)
+        thr = _threshold(hist_ref[...], nsel_ref[0], d)
+        thr_ref[...] = thr
+        if block_skip:
+            thrmin_ref[0] = jnp.min(thr)
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
     if block_skip:
         def _block_live():
-            return blkmax_ref[i, 0] >= jnp.min(thr_ref[...])
+            return blkmax_ref[i] >= thrmin_ref[0]
     else:
         def _block_live():
             return jnp.asarray(True)
@@ -159,11 +174,12 @@ def decode_attention(q_bits: Array, k_bits_planes: Array, v: Array, *,
         out_specs=pl.BlockSpec((1, g, dv), lambda bh, ph, i: (bh, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((bhk, g, dv), jnp.float32),
         scratch_shapes=[
-            pltpu.VMEM((g, d + 1), jnp.int32),   # histogram
+            pltpu.VMEM((g, d + 1), jnp.int32),   # at-or-above counts
             pltpu.VMEM((g, 1), jnp.int32),       # threshold
             pltpu.VMEM((g, dv), jnp.float32),    # numerator
             pltpu.VMEM((g, 1), jnp.float32),     # denominator
-            pltpu.VMEM((t // bt, 1), jnp.int32), # per-block max (skip list)
+            pltpu.SMEM((t // bt,), jnp.int32),   # per-block max (skip list)
+            pltpu.SMEM((1,), jnp.int32),         # min threshold over rows
         ],
         interpret=interpret,
     )(lengths, nsel, scale, q_bits, k_bits_planes, v)

@@ -24,8 +24,8 @@ page_topn >= resident pages nothing is dropped and the result is
 bit-identical to dense paged decode.
 
 Grid: (B*Hk, n_blocks); the block table is a scalar-prefetch operand
-exactly as in the phase-2 decode kernel, and per-block valid counts live
-in SMEM. Phase 1 reads O(context * d/8) bytes of bit-planes; phase 2
+exactly as in the phase-2 decode kernel, one row per slot, and per-block
+valid counts live in SMEM. Phase 1 reads O(context * d/8) bytes of bit-planes; phase 2
 then reads only the selected pages' k_bits AND v — the O(context) fp V
 gather is what this pass eliminates.
 """
@@ -42,10 +42,10 @@ Array = jax.Array
 
 
 def _page_score_kernel(bt_ref, cnt_ref, q_ref, k_ref, o_ref, *,
-                       d: int, page: int):
+                       d: int, page: int, n_kv_heads: int):
     bh = pl.program_id(0)
     i = pl.program_id(1)
-    nv = cnt_ref[bh, i]                     # valid tokens in this block
+    nv = cnt_ref[bh // n_kv_heads, i]       # valid tokens in this block
     k = k_ref[0, 0]                         # [W, page] uint32 bit-planes
     w = k.shape[0]
     off = jax.lax.broadcasted_iota(jnp.int32, (w, page), 1)
@@ -58,11 +58,15 @@ def _page_score_kernel(bt_ref, cnt_ref, q_ref, k_ref, o_ref, *,
     qshift = jax.lax.broadcasted_iota(jnp.uint32, (1, 1, 32), 2)
     qbit = (jax.lax.shift_right_logical(q[:, :, None], qshift)
             & jnp.uint32(1)).reshape(g, w * 32)
-    match = jnp.where(qbit == jnp.uint32(1), cnt > 0, cnt < nv)
+    match = jnp.where(qbit == jnp.uint32(1), (cnt > 0).astype(jnp.int32),
+                      (cnt < nv).astype(jnp.int32))
     live = jax.lax.broadcasted_iota(jnp.int32, (1, w * 32), 1) < d
-    match = jnp.logical_and(match, live)    # zero-padded tail bits: ignore
-    ub = 2 * jnp.sum(match.astype(jnp.int32), axis=1) - d    # [G]
-    o_ref[0, 0] = jnp.max(ub)
+    match = jnp.where(live, match, 0)       # zero-padded tail bits: ignore
+    ub = 2 * jnp.sum(match, axis=1, keepdims=True) - d
+    # the row's scores stay resident across i: fill lane i by a select
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, o_ref.shape[-1]), 1)
+    o_ref[0] = jnp.where(lane == i, jnp.max(ub, axis=0, keepdims=True),
+                         o_ref[0])
 
 
 def paged_page_scores(q_bits: Array, k_pool: Array, block_tables: Array,
@@ -73,10 +77,10 @@ def paged_page_scores(q_bits: Array, k_pool: Array, block_tables: Array,
     Args:
       q_bits: [B*Hk, G, W] uint32 — new-token query bits per KV head.
       k_pool: [n_pages, Hk, W, page] uint32 — paged K bit-planes.
-      block_tables: [B*Hk, n_blocks] int32 physical page ids per row
+      block_tables: [B, n_blocks] int32 physical page ids per slot
         (>= 0; entries with count 0 may alias any page — their score is
         -d and the caller masks them out of selection anyway).
-      counts: [B*Hk, n_blocks] int32 valid tokens per listed block.
+      counts: [B, n_blocks] int32 valid tokens per listed block.
       d: head dimension (bits). n_kv_heads: Hk.
 
     Returns: [B*Hk, n_blocks] int32 per-page upper-bound scores (max
@@ -85,28 +89,30 @@ def paged_page_scores(q_bits: Array, k_pool: Array, block_tables: Array,
     bhk, g, w = q_bits.shape
     n_pages, hk, w2, page = k_pool.shape
     assert w == w2 and hk == n_kv_heads
-    bhk2, nb = block_tables.shape
-    assert bhk2 == bhk and counts.shape == (bhk, nb)
-    kernel = functools.partial(_page_score_kernel, d=d, page=page)
+    b, nb = block_tables.shape
+    assert b * hk == bhk and counts.shape == (b, nb)
+    kernel = functools.partial(_page_score_kernel, d=d, page=page,
+                               n_kv_heads=hk)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(bhk, nb),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # counts [B*Hk, nb]
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # counts [B, nb]
             pl.BlockSpec((1, g, w), lambda bh, i, bt: (bh, 0, 0)),
             pl.BlockSpec((1, 1, w, page),
-                         lambda bh, i, bt: (bt[bh, i],
+                         lambda bh, i, bt: (bt[bh // n_kv_heads, i],
                                             bh % n_kv_heads, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1), lambda bh, i, bt: (bh, i)),
+        out_specs=pl.BlockSpec((1, 1, nb), lambda bh, i, bt: (bh, 0, 0)),
         scratch_shapes=[],
     )
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bhk, nb), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((bhk, 1, nb), jnp.int32),
         interpret=interpret,
     )(block_tables, counts, q_bits, k_pool)
+    return out.reshape(bhk, nb)
 
 
 def page_score_bounds(q_bits: Array, k_bits_bp: Array, lengths: Array, *,

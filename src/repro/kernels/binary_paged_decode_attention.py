@@ -13,13 +13,14 @@ of a contiguous cache. The block table is a *scalar-prefetch* operand
 ``block_tables[bh, i]`` to pick the physical page DMA'd for sequence
 block i — the "block-table prefetch inner loop".
 
-The table is per (batch, kv-head) ROW — not per slot — so the caller can
-hand each row a *compacted* table of selected pages (top-N page-sparse
-decode, phase 2) while the dense path simply broadcasts the slot's table
-over its kv heads. Because compaction breaks the ``i*page + off`` logical
-position arithmetic, per-token validity comes from ``counts[bh, i]`` —
-the number of valid tokens in row bh's i-th listed block — instead of a
-per-row total length. Blocks are listed in ascending logical order, so
+A table row serves ``rep`` consecutive (batch, kv-head) grid rows: the
+dense path passes one row per slot (rep = Hk), so its tables and counts,
+both held in SMEM, do not grow with the kv-head count, while a
+*compacted* table of selected pages (top-N page-sparse decode, phase 2)
+has one row per (batch, kv-head) (rep = 1). Because compaction breaks
+the ``i*page + off`` logical position arithmetic, per-token validity
+comes from ``counts[r, i]`` — the number of valid tokens in table row
+r's i-th listed block — instead of a per-row total length. Blocks are listed in ascending logical order, so
 the accumulation order (and thus the floating-point result) is
 bit-identical to the contiguous kernel with block_t == page whenever the
 listed blocks cover the context.
@@ -39,15 +40,17 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.binary_decode_attention import _scores, _threshold
+from repro.kernels.binary_decode_attention import (_at_or_above, _scores,
+                                                   _threshold)
 
 Array = jax.Array
 
 
 def _paged_decode_kernel(bt_ref, cnt_ref, nsel_ref, scale_ref,
                          q_ref, k_ref, v_ref, o_ref,
-                         hist_ref, thr_ref, num_ref, den_ref, blkmax_ref, *,
-                         d: int, page: int, block_skip: bool):
+                         hist_ref, thr_ref, num_ref, den_ref, blkmax_ref,
+                         thrmin_ref, *,
+                         d: int, page: int, rep: int, block_skip: bool):
     bh = pl.program_id(0)
     ph = pl.program_id(1)
     i = pl.program_id(2)
@@ -59,7 +62,7 @@ def _paged_decode_kernel(bt_ref, cnt_ref, nsel_ref, scale_ref,
         k = k_ref[0, 0]         # [W, page] — page picked by the index map
         s = _scores(q, k, d)    # [G, page] int32
         off = jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        return s, off < cnt_ref[bh, i]
+        return s, off < cnt_ref[bh // rep, i]
 
     @pl.when((ph == 0) & (i == 0))
     def _init_hist():
@@ -68,23 +71,22 @@ def _paged_decode_kernel(bt_ref, cnt_ref, nsel_ref, scale_ref,
     @pl.when(ph == 0)
     def _accum_hist():
         s, valid = scores_valid()
-        levels = (s + d) // 2                                    # [G, page]
-        onehot = (levels[:, :, None] ==
-                  jax.lax.broadcasted_iota(jnp.int32, (1, 1, d + 1), 2))
-        onehot = jnp.logical_and(onehot, valid[:, :, None])
-        hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1)
+        hist_ref[...] += _at_or_above(s, valid, d)
         if block_skip:
-            blkmax_ref[i, 0] = jnp.max(jnp.where(valid, s, -d - 2))
+            blkmax_ref[i] = jnp.max(jnp.where(valid, s, -d - 2))
 
     @pl.when((ph == 0) & (i == nb - 1))
     def _finalize_threshold():
-        thr_ref[...] = _threshold(hist_ref[...], nsel_ref[0], d)
+        thr = _threshold(hist_ref[...], nsel_ref[0], d)
+        thr_ref[...] = thr
+        if block_skip:
+            thrmin_ref[0] = jnp.min(thr)
         num_ref[...] = jnp.zeros_like(num_ref)
         den_ref[...] = jnp.zeros_like(den_ref)
 
     if block_skip:
         def _block_live():
-            return blkmax_ref[i, 0] >= jnp.min(thr_ref[...])
+            return blkmax_ref[i] >= thrmin_ref[0]
     else:
         def _block_live():
             return jnp.asarray(True)
@@ -117,13 +119,16 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
       q_bits: [B*Hk, G, W] uint32 — new-token query bits per KV head.
       k_pool: [n_pages, Hk, W, page] uint32 — paged K bit-planes.
       v_pool: [n_pages, Hk, page, Dv] — paged V.
-      block_tables: [B*Hk, n_blocks] int32 physical page ids PER ROW
-        (>= 0; entries with count 0 may alias any page — masked). Rows
-        list their blocks in ascending logical order; a compacted table
-        (page-sparse phase 2) lists only the selected pages.
+      block_tables: [R, n_blocks] int32 physical page ids, R dividing
+        B*Hk: table row r serves grid rows r*rep .. r*rep + rep-1,
+        rep = B*Hk // R (R = B: one table per slot; R = B*Hk: one per
+        (batch, kv-head) row). Entries are >= 0; those with count 0 may
+        alias any page — masked. Rows list their blocks in ascending
+        logical order; a compacted table (page-sparse phase 2) lists only
+        the selected pages.
       d: head dimension (bits).
       nsel: [1] int32 top-N; scale: [1] float32 logit scale.
-      counts: [B*Hk, n_blocks] int32 valid tokens per listed block.
+      counts: [R, n_blocks] int32 valid tokens per listed block.
       n_kv_heads: Hk (maps grid row -> kv head for the pool index).
 
     Returns: [B*Hk, G, Dv] float32 attention outputs.
@@ -133,33 +138,35 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
     n_pages_v, hk2, page2, dv = v_pool.shape
     assert w == w2 and page == page2 and hk == hk2 == n_kv_heads
     assert n_pages_k == n_pages_v
-    bhk2, nb = block_tables.shape
-    assert bhk2 == bhk and counts.shape == (bhk, nb), \
+    r, nb = block_tables.shape
+    assert bhk % r == 0 and counts.shape == (r, nb), \
         (block_tables.shape, counts.shape, bhk)
+    rep = bhk // r
     kernel = functools.partial(_paged_decode_kernel, d=d, page=page,
-                               block_skip=block_skip)
+                               rep=rep, block_skip=block_skip)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,          # block_tables feeds the index maps
         grid=(bhk, 2, nb),
         in_specs=[
-            pl.BlockSpec(memory_space=pltpu.SMEM),  # counts [B*Hk, nb]
+            pl.BlockSpec(memory_space=pltpu.SMEM),  # counts [R, nb]
             pl.BlockSpec(memory_space=pltpu.SMEM),  # nsel [1]
             pl.BlockSpec(memory_space=pltpu.SMEM),  # scale [1]
             pl.BlockSpec((1, g, w), lambda bh, ph, i, bt: (bh, 0, 0)),
             pl.BlockSpec((1, 1, w, page),
-                         lambda bh, ph, i, bt: (bt[bh, i],
+                         lambda bh, ph, i, bt: (bt[bh // rep, i],
                                                 bh % n_kv_heads, 0, 0)),
             pl.BlockSpec((1, 1, page, dv),
-                         lambda bh, ph, i, bt: (bt[bh, i],
+                         lambda bh, ph, i, bt: (bt[bh // rep, i],
                                                 bh % n_kv_heads, 0, 0)),
         ],
         out_specs=pl.BlockSpec((1, g, dv), lambda bh, ph, i, bt: (bh, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, d + 1), jnp.int32),   # histogram
+            pltpu.VMEM((g, d + 1), jnp.int32),   # at-or-above counts
             pltpu.VMEM((g, 1), jnp.int32),       # threshold
             pltpu.VMEM((g, dv), jnp.float32),    # numerator
             pltpu.VMEM((g, 1), jnp.float32),     # denominator
-            pltpu.VMEM((nb, 1), jnp.int32),      # per-block max (skip list)
+            pltpu.SMEM((nb,), jnp.int32),        # per-block max (skip list)
+            pltpu.SMEM((1,), jnp.int32),         # min threshold over rows
         ],
     )
     return pl.pallas_call(

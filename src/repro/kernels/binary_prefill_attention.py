@@ -75,11 +75,18 @@ def _prefill_kernel(len_ref, nsel_ref, scale_ref, qoff_ref, qlen_ref,
 
         @pl.when(ph == 0)
         def _accum_hist():
-            levels = (s + d) // 2
-            onehot = (levels[:, :, None] ==
-                      jax.lax.broadcasted_iota(jnp.int32, (1, 1, d + 1), 2))
-            onehot = jnp.logical_and(onehot, valid[:, :, None])
-            hist_ref[...] += jnp.sum(onehot.astype(jnp.int32), axis=1)
+            # one level per iteration: a [bq, bt, d+1] compare would not
+            # fit VMEM at serving block sizes
+            lv = jnp.where(valid, (s + d) >> 1, -1)         # [bq, bt]
+            lane = jax.lax.broadcasted_iota(jnp.int32, (1, d + 1), 1)
+
+            def level(l, cc):
+                col = jnp.sum((lv >= l).astype(jnp.int32), axis=1,
+                              keepdims=True)
+                return cc + jnp.where(lane == l, col, 0)
+
+            hist_ref[...] += jax.lax.fori_loop(
+                0, d + 1, level, jnp.zeros(hist_ref.shape, jnp.int32))
 
         @pl.when(ph == 1)
         def _accum_softmax():

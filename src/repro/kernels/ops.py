@@ -1,13 +1,12 @@
 """jit'd public wrappers over the Pallas kernels.
 
 Handle layout (row-major <-> bit-plane), GQA grouping, padding to block
-multiples, and the interpret-mode switch (CPU containers run the kernel
-bodies in Python via interpret=True; on TPU set REPRO_PALLAS_INTERPRET=0).
+multiples, and the interpret-mode switch: the kernels run compiled on a
+TPU, and in interpret mode (Python-executed bodies) on the CPU backend.
 """
 from __future__ import annotations
 
 import functools
-import os
 
 import jax
 import jax.numpy as jnp
@@ -22,11 +21,16 @@ from repro.kernels import hamming_score as _hs
 Array = jax.Array
 
 
-def default_interpret() -> bool:
-    env = os.environ.get("REPRO_PALLAS_INTERPRET")
-    if env is not None:
-        return env not in ("0", "false", "False")
-    return jax.default_backend() != "tpu"
+def resolve_interpret(interpret: bool | None) -> bool:
+    """A wrapper's `interpret` argument. None means interpret mode on the
+    CPU backend only; asking for it on a TPU raises instead of running
+    the Python interpreter in place of the compiled kernel."""
+    if interpret is None:
+        return jax.default_backend() == "cpu"
+    if interpret and jax.default_backend() == "tpu":
+        raise ValueError("Pallas interpret mode was requested on a TPU; "
+                         "the kernels run compiled there")
+    return interpret
 
 
 def to_bitplanes(k_bits: Array) -> Array:
@@ -54,7 +58,7 @@ def hamming_scores(q_bits: Array, k_bits: Array, d: int, *,
 
     q_bits: [..., M, W]; k_bits: [..., N, W] -> [..., M, N] int32.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     lead = q_bits.shape[:-2]
     m, w = q_bits.shape[-2:]
     n = k_bits.shape[-2]
@@ -84,7 +88,7 @@ def decode_attention(q_bits: Array, k_bits: Array, v: Array, *, d: int,
     [B, Hk, W, T] when bitplanes=True; v: [B, Hk, T, Dv];
     lengths: [B] int32 valid cache lengths. Returns [B, H, Dv] f32.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, h, w = q_bits.shape
     if bitplanes:
         _, hk, w2, t = k_bits.shape
@@ -109,19 +113,15 @@ def decode_attention(q_bits: Array, k_bits: Array, v: Array, *, d: int,
     return out.reshape(b, h, dv)
 
 
-def _row_tables(block_tables: Array, lengths: Array, hk: int,
-                page: int) -> tuple[Array, Array, Array]:
-    """Per-slot [B, nb] table + [B] lengths -> per-(slot, kv-head) ROW
-    tables [B*Hk, nb] (clamped in range), per-block valid counts
-    [B*Hk, nb], and per-row lengths [B*Hk]."""
+def _slot_tables(block_tables: Array, lengths: Array,
+                 page: int) -> tuple[Array, Array]:
+    """Per-slot [B, nb] table + [B] lengths -> the table clamped in range
+    and per-block valid counts [B, nb]."""
     bt = jnp.maximum(jnp.asarray(block_tables, jnp.int32), 0)
-    b, nb = bt.shape
-    bt_rows = jnp.repeat(bt, hk, axis=0)
-    len_f = jnp.broadcast_to(jnp.asarray(lengths, jnp.int32)[:, None],
-                             (b, hk)).reshape(-1)
-    counts = jnp.clip(len_f[:, None] -
+    nb = bt.shape[1]
+    counts = jnp.clip(jnp.asarray(lengths, jnp.int32)[:, None] -
                       jnp.arange(nb, dtype=jnp.int32)[None] * page, 0, page)
-    return bt_rows, counts.astype(jnp.int32), len_f
+    return bt, counts.astype(jnp.int32)
 
 
 def select_pages(scores: Array, block_tables: Array, lengths: Array, *,
@@ -197,7 +197,7 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
     group structure must survive the split, i.e. Hk % tp == 0 (enforced
     by serve/validate.py) so h/hk stays the global group size g.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, h, w = q_bits.shape
     _, hk, w2, page = k_pool.shape
     assert w == w2
@@ -206,15 +206,19 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
     dv = v_pool.shape[-1]
     nb = block_tables.shape[1]
     qf = q_bits.reshape(b, hk, g, w).reshape(b * hk, g, w)
-    bt_rows, counts, len_f = _row_tables(block_tables, lengths, hk, page)
+    # dense: one table row per slot, shared by its kv heads; top-N: one
+    # compacted row per (slot, kv-head)
+    tables, counts = _slot_tables(block_tables, lengths, page)
     if page_topn is not None and page_topn < nb:
-        scores = _pscore.paged_page_scores(qf, k_pool, bt_rows, counts,
+        scores = _pscore.paged_page_scores(qf, k_pool, tables, counts,
                                            d=d, n_kv_heads=hk,
                                            interpret=interpret)
-        bt_rows, counts, _ = select_pages(scores, bt_rows, len_f,
-                                          page=page, n_sel=page_topn)
+        tables, counts, _ = select_pages(
+            scores, jnp.repeat(tables, hk, axis=0),
+            jnp.repeat(jnp.asarray(lengths, jnp.int32), hk),
+            page=page, n_sel=page_topn)
     out = _pdec.paged_decode_attention(
-        qf, k_pool, v_pool, bt_rows,
+        qf, k_pool, v_pool, tables,
         d=d, nsel=jnp.asarray([nsel], dtype=jnp.int32).reshape(1),
         scale=jnp.asarray([scale], dtype=jnp.float32).reshape(1),
         counts=counts, n_kv_heads=hk,
@@ -241,7 +245,7 @@ def prefill_attention(q_bits: Array, k_bits: Array, v: Array, *, d: int,
     are skipped in the kernel (zero output rows).
     Returns [B, H, S, Dv] float32.
     """
-    interpret = default_interpret() if interpret is None else interpret
+    interpret = resolve_interpret(interpret)
     b, h, s, w = q_bits.shape
     _, hk, t, w2 = k_bits.shape
     assert w == w2

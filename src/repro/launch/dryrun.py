@@ -285,6 +285,8 @@ def main():
     meshes = {"single": [False], "multi": [True],
               "both": [False, True]}[args.mesh]
 
+    print(f"roofline terms model one {RL.MODELED_KIND!r} chip's "
+          f"published peaks (analytic, not measured)", flush=True)
     records = []
     for arch in archs:
         for shape in shapes:

@@ -8,13 +8,14 @@ tests/benches must keep seeing 1 device.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single pod (256 chips) or 2x16x16 two pods (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh(*, data: int | None = None, model: int = 1):
@@ -38,4 +39,11 @@ def make_host_mesh(*, data: int | None = None, model: int = 1):
             f"shrink the mesh, or force host devices with "
             f"XLA_FLAGS=--xla_force_host_platform_device_count=N (set "
             f"before jax initializes)")
-    return jax.make_mesh((data, model), ("data", "model"))
+    return _auto_mesh((data, model), ("data", "model"))
+
+
+def _auto_mesh(shape: tuple[int, ...], axes: tuple[str, ...]):
+    """jax.make_mesh with Auto axes: the sharding rules here place arrays
+    with NamedSharding / with_sharding_constraint, which Explicit axes
+    (make_mesh's default) refuse."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))

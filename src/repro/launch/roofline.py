@@ -2,9 +2,9 @@
 
 Three terms per (arch, shape, mesh), in seconds (EXPERIMENTS.md §Roofline):
 
-    compute    = HLO_FLOPs / (chips * PEAK_FLOPS)
-    memory     = HLO_bytes / (chips * HBM_BW)
-    collective = collective_bytes / (chips * ICI_BW)
+    compute    = HLO_FLOPs / (chips * peak bf16 FLOP/s)
+    memory     = HLO_bytes / (chips * HBM bandwidth)
+    collective = collective_bytes / (chips * ICI bandwidth per link)
 
 Implementation note (validated against an analytic matmul): after SPMD
 partitioning, compiled.cost_analysis() / memory_analysis() / as_text() all
@@ -14,17 +14,42 @@ as flops * chips. Collective bytes are parsed from the per-device HLO text —
 summed operand sizes of all-gather / all-reduce / reduce-scatter /
 all-to-all / collective-permute.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16 per chip, 819 GB/s HBM,
-~50 GB/s/link ICI (assignment-provided).
+Chip peaks live in one table keyed by JAX's `device_kind`; a kind that is
+not in the table raises instead of falling back to another chip's numbers.
+The analytic dry-run models `MODELED_KIND` and records it in every cell.
 """
 from __future__ import annotations
 
 import dataclasses
 import re
 
-PEAK_FLOPS = 197e12      # bf16 / chip
-HBM_BW = 819e9           # bytes/s / chip
-ICI_BW = 50e9            # bytes/s / link (per chip, one link budgeted)
+
+@dataclasses.dataclass(frozen=True)
+class ChipPeaks:
+    flops_bf16: float        # FLOP/s per chip
+    hbm_bw: float            # bytes/s per chip
+    ici_bw: float            # bytes/s per link (one link budgeted)
+
+
+# Source: Google Cloud documentation, "TPU v5e" (system architecture):
+# 197 TFLOP/s bf16, 16 GB HBM at 819 GB/s, 1,600 Gbit/s of inter-chip
+# interconnect per chip over 4 links (50 GB/s per link).
+CHIP_PEAKS: dict[str, ChipPeaks] = {
+    "TPU v5 lite": ChipPeaks(flops_bf16=197e12, hbm_bw=819e9, ici_bw=50e9),
+}
+
+MODELED_KIND = "TPU v5 lite"
+
+
+def chip_peaks(device_kind: str) -> ChipPeaks:
+    """Published peaks of one chip of this `jax.Device.device_kind`."""
+    try:
+        return CHIP_PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device_kind "
+                       f"{device_kind!r}; known: {sorted(CHIP_PEAKS)}") \
+            from None
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -92,15 +117,15 @@ class RooflineTerms:
 
     @property
     def t_compute(self) -> float:
-        return self.flops / PEAK_FLOPS
+        return self.flops / chip_peaks(MODELED_KIND).flops_bf16
 
     @property
     def t_memory(self) -> float:
-        return self.bytes_hbm / HBM_BW
+        return self.bytes_hbm / chip_peaks(MODELED_KIND).hbm_bw
 
     @property
     def t_collective(self) -> float:
-        return self.bytes_collective / ICI_BW
+        return self.bytes_collective / chip_peaks(MODELED_KIND).ici_bw
 
     @property
     def dominant(self) -> str:
@@ -117,6 +142,7 @@ class RooflineTerms:
             "flops": self.flops, "global_flops": self.global_flops,
             "bytes_hbm": self.bytes_hbm,
             "bytes_collective": self.bytes_collective, "chips": self.chips,
+            "device_kind": MODELED_KIND,
             "t_compute_s": self.t_compute, "t_memory_s": self.t_memory,
             "t_collective_s": self.t_collective, "dominant": self.dominant,
         }

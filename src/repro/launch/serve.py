@@ -18,15 +18,94 @@ telemetry), and the SLO-attaining request rate vs the raw rate.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
+from typing import Callable
 
 import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import model as M
 from repro.serve import (Engine, SamplingParams, ServeConfig, Telemetry,
                          slo_attainment)
+
+
+def make_prompts(seed: int, n: int, lo: int, hi: int,
+                 vocab: int) -> list[np.ndarray]:
+    """`n` prompts from one seed: lengths uniform in [lo, hi), token ids
+    uniform in [0, vocab)."""
+    rng = np.random.default_rng(seed)
+    lens = rng.integers(lo, hi, size=n)
+    return [rng.integers(0, vocab, size=int(s)) for s in lens]
+
+
+@dataclasses.dataclass
+class Served:
+    """What one `drive` returns: request ids in submission order, each
+    request's generated tokens, the lifecycle records the engine emitted,
+    and the loop's host wall time."""
+    ids: list[int]
+    tokens: dict[int, np.ndarray]
+    metrics: list
+    seconds: float
+
+
+def drive(eng: Engine, prompts: list[np.ndarray], *, gen: int,
+          sampling: SamplingParams | None = None, stagger: int = 0,
+          warm: int = 0, pipelined: bool = False,
+          on_token: Callable[[int, int, int], None] | None = None) -> Served:
+    """Serve `prompts` through `eng` until every request has finished.
+
+    With `stagger` > 0, `warm` requests are submitted up front and one
+    more every `stagger` steps while residents decode; otherwise all are
+    submitted at once. `pipelined` drives the double-buffered
+    `step_pipelined()` loop. Every token reaches `on_token(request_id,
+    index, token)` the step it commits (the scheduler's token sink), and
+    the streamed sequences must equal the finished arrays.
+    """
+    streamed: dict[int, list[int]] = {}
+
+    def sink(rid: int, tok: int) -> None:
+        toks = streamed.setdefault(rid, [])
+        toks.append(int(tok))
+        if on_token is not None:
+            on_token(rid, len(toks) - 1, int(tok))
+
+    eng.scheduler.token_sink = sink
+    step = eng.step_pipelined if pipelined else eng.step
+    n_req = len(prompts)
+    warm = min(warm, n_req) if stagger else n_req
+
+    def submit(i: int) -> None:
+        ids.append(eng.submit(prompts[i], max_new_tokens=gen,
+                              sampling=sampling))
+
+    t0 = time.perf_counter()
+    ids: list[int] = []
+    results: dict[int, np.ndarray] = {}
+    metrics: list = []
+    for i in range(warm):
+        submit(i)
+    next_req = warm
+    steps = 0
+    while eng.queue or any(s.request is not None for s in eng.slots) \
+            or next_req < n_req \
+            or (pipelined and eng._inflight is not None):
+        for fr in step():
+            results[fr.request_id] = fr.tokens
+        metrics += eng.pop_finished_metrics()
+        steps += 1
+        if stagger and next_req < n_req and steps % stagger == 0:
+            submit(next_req)
+            next_req += 1
+    dt = time.perf_counter() - t0
+    metrics += eng.pop_finished_metrics()
+    for rid in ids:
+        assert streamed.get(rid, []) == results[rid].tolist(), (
+            f"req {rid}: streamed tokens diverge from the finished array")
+    return Served(ids, results, metrics, dt)
 
 
 def main():
@@ -123,17 +202,17 @@ def main():
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_config(args.arch, reduced=args.reduced)
     if cfg.is_encoder:
         raise SystemExit(f"{cfg.name} is encoder-only — no decode loop")
     params = M.init_params(jax.random.PRNGKey(args.seed), cfg)
     n_req = args.requests or 2 * args.slots
-    rng = np.random.default_rng(args.seed)
     lo = max(1, int(args.prompt_len * (1 - args.len_spread)))
     hi = max(lo + 1, int(args.prompt_len * (1 + args.len_spread)) + 1)
-    lens = rng.integers(lo, hi, size=n_req)
-    prompts = [rng.integers(0, cfg.vocab_size, size=int(s)) for s in lens]
-    max_len = int(max(lens)) + args.gen
+    prompts = make_prompts(args.seed, n_req, lo, hi, cfg.vocab_size)
+    lens = [int(p.size) for p in prompts]
+    max_len = max(lens) + args.gen
     binary = not args.baseline and cfg.had.enabled and cfg.has_attention
     paged = (args.paged or args.prefix_cache or bool(args.swap_pages)
              or bool(args.page_topn))
@@ -166,53 +245,20 @@ def main():
     sampling = SamplingParams(temperature=args.temperature,
                               top_k=args.top_k, seed=args.seed)
 
-    # per-token streaming: the scheduler hands every sampled token to the
-    # sink the step it commits — the whole sequence is assembled from the
-    # stream, and the finished-request arrays must agree with it
-    streamed: dict[int, list[int]] = {}
+    def on_token(rid: int, i: int, tok: int) -> None:
+        print(f"  + req {rid}[{i}] = {tok}", flush=True)
 
-    def sink(rid: int, tok: int) -> None:
-        toks = streamed.setdefault(rid, [])
-        toks.append(int(tok))
-        if args.stream:
-            print(f"  + req {rid}[{len(toks) - 1}] = {int(tok)}",
-                  flush=True)
-
-    eng.scheduler.token_sink = sink
-    step = eng.step_pipelined if args.async_mode else eng.step
-
-    t0 = time.perf_counter()
-    pending = list(range(n_req))
-    results: dict[int, np.ndarray] = {}
-    ids: list[int] = []
-    # staggered arrivals: trickle requests in while resident slots decode
-    warm = args.slots if args.stagger else n_req
-    for i in pending[:warm]:
-        ids.append(eng.submit(prompts[i], max_new_tokens=args.gen,
-                              sampling=sampling))
-    next_req = warm
-    steps = 0
-    req_metrics = []
-    while eng.queue or any(s.request is not None for s in eng.slots) \
-            or next_req < n_req \
-            or (args.async_mode and eng._inflight is not None):
-        for fr in step():
-            results[fr.request_id] = fr.tokens
-        req_metrics += eng.pop_finished_metrics()
-        steps += 1
-        if args.stagger and next_req < n_req and steps % args.stagger == 0:
-            ids.append(eng.submit(prompts[next_req], max_new_tokens=args.gen,
-                                  sampling=sampling))
-            next_req += 1
-    dt = time.perf_counter() - t0
-    req_metrics += eng.pop_finished_metrics()
+    served = drive(eng, prompts, gen=args.gen, sampling=sampling,
+                   stagger=args.stagger, warm=args.slots,
+                   pipelined=args.async_mode,
+                   on_token=on_token if args.stream else None)
+    ids, results, dt = served.ids, served.tokens, served.seconds
+    req_metrics = served.metrics
 
     gen_tok = eng.stats["tokens_generated"]
     print(f"arch={cfg.name} binary={binary} N={eng.n} slots={args.slots} "
-          f"requests={n_req} prompt_lens={lens.tolist()} gen={args.gen}")
+          f"requests={n_req} prompt_lens={lens} gen={args.gen}")
     for rid in ids:
-        assert streamed.get(rid, []) == results[rid].tolist(), (
-            f"req {rid}: streamed tokens diverge from the finished array")
         print(f"  req {rid}: {results[rid].tolist()}")
     print(f"wall {dt:.2f}s  decode_steps={eng.stats['decode_steps']} "
           f"prefill_chunks={eng.stats['prefill_chunks']} "

@@ -23,6 +23,7 @@ from repro.core.distill import DistillConfig, tiny_schedule
 from repro.data import lm_stream, shard_batches
 from repro.distributed import sharding as SH
 from repro.distributed.compression import CompressionConfig
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh
 from repro.models import model as M
 from repro.optim import adam
@@ -50,6 +51,7 @@ def main():
     ap.add_argument("--log", default=None)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_config(args.arch, reduced=args.reduced)
     mode = args.mode

@@ -152,6 +152,9 @@ class ModelRunner:
         self.stats.declare_counters(SERVE_COUNTERS)
         # optional observability hub (set by the Engine)
         self.telemetry = None
+        # optional hook: called (request_id, logits row [vocab] numpy)
+        # with the logits of every sampled token, in sampling order
+        self.logits_sink = None
         validate_serve_features(cfg.layer_pattern, scfg)
         validate_serve_mesh(cfg, scfg)
         # tensor-parallel serving (ServeConfig.mesh, model axis > 1): the
@@ -225,7 +228,6 @@ class ModelRunner:
         step. Same static argnames -> the 1-prefill + 1-decode trace pin
         holds per mesh size.
         """
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec
 
         cfg, mesh, tp = self.cfg, self.mesh, self._tp
@@ -253,11 +255,11 @@ class ModelRunner:
                                     page_topn=page_topn,
                                     state_tables=state_tables,
                                     axis_name="model")
-            fn = shard_map(body, mesh=mesh,
-                           in_specs=(param_ps, rep, cache_ps, rep, rep,
-                                     rep, rep, rep),
-                           out_specs=(rep, cache_ps),
-                           check_rep=False)
+            fn = jax.shard_map(body, mesh=mesh,
+                               in_specs=(param_ps, rep, cache_ps, rep, rep,
+                                         rep, rep, rep),
+                               out_specs=(rep, cache_ps),
+                               check_vma=False)
             return fn(params, batch, caches, pos, active, n_valid,
                       block_tables, state_tables)
         return _step
@@ -443,8 +445,10 @@ class ModelRunner:
                 self._state_copy(int(plan.state_tables[ch.slot]),
                                  ch.state_ckpt)
             if ch.samples:
-                tok = _sample_token(np.asarray(logits[ch.slot, 0, :vocab]),
-                                    req.sampling, ch.rng)
+                row = np.asarray(logits[ch.slot, 0, :vocab])
+                if self.logits_sink is not None:
+                    self.logits_sink(req.request_id, row)
+                tok = _sample_token(row, req.sampling, ch.rng)
                 sampled[ch.slot] = tok
                 results[ch.slot].append(tok)
                 if ch.eos_token is not None and tok == ch.eos_token:
@@ -475,6 +479,8 @@ class ModelRunner:
             vocab = self.cfg.vocab_size
             rows = np.asarray(pending.logits[:, 0, :vocab])
             for e in pending.entries:
+                if self.logits_sink is not None:
+                    self.logits_sink(e.request.request_id, rows[e.slot])
                 tok = _sample_token(rows[e.slot], e.sampling, e.rng)
                 pending.results.setdefault(e.slot, []).append(tok)
             pending.logits = None

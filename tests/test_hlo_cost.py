@@ -83,10 +83,10 @@ def test_elementwise_assumed_fused():
 
 def test_collectives_in_loop_multiplied():
     import os
-    from jax.sharding import NamedSharding, PartitionSpec as P
+    from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
     if len(jax.devices()) < 1:
         pytest.skip("no devices")
-    mesh = jax.make_mesh((1,), ("data",))
+    mesh = jax.make_mesh((1,), ("data",), axis_types=(AxisType.Auto,))
     sh = NamedSharding(mesh, P("data"))
     x = jnp.ones((8, 64), jnp.float32)
 

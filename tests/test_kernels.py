@@ -427,10 +427,10 @@ def test_page_score_kernel_matches_ref(d, hk):
     qb, kb, _, k_pool, _, bt = _make_pool(b, h, hk, nb, page, d, 8,
                                           n_pages=b * nb + 2, seed=d)
     lengths = jnp.asarray([nb * page, 3 * page - 5], jnp.int32)
-    bt_rows, counts, _ = ops._row_tables(bt, lengths, hk, page)
+    tables, counts = ops._slot_tables(bt, lengths, page)
     qf = qb.reshape(b, hk, g, -1).reshape(b * hk, g, -1)
     from repro.kernels import binary_page_score as PS
-    got = PS.paged_page_scores(qf, k_pool, bt_rows, counts, d=d,
+    got = PS.paged_page_scores(qf, k_pool, tables, counts, d=d,
                                n_kv_heads=hk, interpret=True)
     want = ref.page_scores_ref(qb.reshape(b, hk, g, -1), k_pool, bt,
                                d=d, lengths=lengths)

@@ -73,6 +73,24 @@ def test_mixed_lengths_match_sequential_kernel_path():
         np.testing.assert_array_equal(got[rid], w)
 
 
+@pytest.mark.parametrize("pipelined", [False, True])
+def test_logits_sink_sees_every_sampled_token(params, pipelined):
+    """The runner's logits sink gets one row per sampled token, prefill
+    completion and decode alike, in order; greedy tokens are its argmax."""
+    rng = np.random.default_rng(2)
+    prompts = [rng.integers(0, 64, n) for n in (11, 4, 7)]
+    eng = Engine(CFG, params, _scfg(2, True))
+    rows: dict[int, list[np.ndarray]] = {}
+    eng.runner.logits_sink = (
+        lambda rid, row: rows.setdefault(rid, []).append(np.array(row)))
+    ids = [eng.submit(p, max_new_tokens=5) for p in prompts]
+    got = eng.run_pipelined() if pipelined else eng.run()
+    for rid in ids:
+        assert len(rows[rid]) == 5
+        np.testing.assert_array_equal(
+            np.argmax(np.stack(rows[rid]), -1), got[rid])
+
+
 HCFG = dataclasses.replace(CFG, name="hyb", family="hybrid",
                            layer_pattern="AM", ssm_state=16,
                            ssm_head_dim=16, ssm_chunk=8)

@@ -100,11 +100,8 @@ def test_int8_compression_accuracy():
 
 def test_psum_compressed_shard_map():
     """1-bit psum inside shard_map approximates the exact mean."""
+    from jax import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
-    try:
-        from jax import shard_map                      # jax >= 0.5
-    except ImportError:
-        from jax.experimental.shard_map import shard_map
     devs = np.array(jax.devices()[:1])
     mesh = Mesh(devs.reshape(1), ("dp",))
     cfg = C.CompressionConfig(method="int8")
