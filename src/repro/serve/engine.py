@@ -11,7 +11,8 @@ scheduler/executor split (vLLM-style):
   * :class:`repro.serve.runner.ModelRunner` — *execution*: the jitted
     serve step, cache pools, sampling, and swapped pages' contents. It
     executes a plan verbatim and returns the sampled tokens.
-  * `Engine.step()` is exactly `commit(plan, execute(schedule()))`.
+  * `Engine.step()` is exactly
+    `commit(plan, wait(execute_async(schedule())))`.
 
 Serving semantics (unchanged public contract):
 
@@ -49,14 +50,25 @@ story end-to-end. All positions/lengths are int32 (the kernels' dtype).
 The low-level `prefill()` / `decode()` methods remain for lockstep use
 (uniform-length batches driven by hand) and for tests; `generate()` is a
 convenience that routes through the scheduler.
+
+Every step phase runs under a `jax.profiler.TraceAnnotation` named
+`serve.<phase>` (the engine's: schedule, dispatch, land, commit, resolve,
+commit_structural; the runner's: prefill_chunk, decode, swap,
+device_wait, sample), so a profiler trace puts each idle stretch of the
+device down to a host phase; with no profiler running a span costs under
+a microsecond. The registry's always-on host counters (host_schedule_s,
+host_overlap_s, host_exposed_s, host_sample_s, admitted, admit_wait_s,
+first_chunks, first_chunk_wait_s) are read from the same step.
 """
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import time
 from typing import Any
 
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.models.config import ModelConfig
 from repro.serve.paged import BlockAllocator, PrefixCache, SwapPool  # noqa: F401 (re-export)
@@ -84,6 +96,7 @@ class _Inflight:
     launch_ts: float                   # execute_async dispatch time
     sched_s: float                     # host time spent building the plan
     structural_s: float                # host time of commit_structural
+    step: int                          # the step index of its schedule()
 
 
 class Engine:
@@ -116,9 +129,13 @@ class Engine:
         self.chunk = self.scheduler.chunk
         # the double buffer: at most ONE dispatched-but-uncommitted step
         self._inflight: _Inflight | None = None
-        # pipelined-mode overlap accounting (seconds): how much host
-        # schedule time was hidden under the previous step's device window
-        self._pipe = {"overlap": 0.0, "schedule": 0.0, "steps": 0}
+        # numbers schedule() calls: the step index on the serve.* spans
+        self._step_idx = itertools.count()
+        # request_id -> perf_counter stamp, held until the request's first
+        # admission (submit time) and then its first prefill chunk's
+        # dispatch (first admission time): the admit / first-chunk waits
+        self._submitted: dict[int, float] = {}
+        self._admitted: dict[int, float] = {}
 
     # ------------------------------------------------------------------
     # facade: shared state lives on the scheduler (host) / runner (device)
@@ -205,13 +222,15 @@ class Engine:
                extra: dict | None = None, priority: str = "batch") -> int:
         """Enqueue a request; returns its request_id. May be called at any
         time — admission happens at the next `step()` if a slot is free."""
-        return self.scheduler.submit(tokens, max_new_tokens,
-                                     eos_token=eos_token, sampling=sampling,
-                                     extra=extra, priority=priority)
+        rid = self.scheduler.submit(tokens, max_new_tokens,
+                                    eos_token=eos_token, sampling=sampling,
+                                    extra=extra, priority=priority)
+        self._submitted[rid] = time.perf_counter()
+        return rid
 
     def step(self) -> list[FinishedRequest]:
         """One synchronous scheduler step — a thin wrapper over the same
-        primitives the pipelined path uses: `execute()` is
+        primitives the pipelined path uses: execution is
         `wait(execute_async(plan))` and `commit()` is
         `commit_structural(plan)` + `commit_tokens(plan, results)`, just
         composed back-to-back with no overlap. Returns newly finished
@@ -225,24 +244,27 @@ class Engine:
         dispatch time."""
         finished = self.flush()
         tel = self.telemetry
-        if tel is None:
+        clock = self._clock()
+        idx = next(self._step_idx)
+        with _span("serve.schedule", step=idx):
+            t0 = clock()
             plan = self.scheduler.schedule()
-            results = self.runner.execute(plan)
-            return finished + self.scheduler.commit(plan, results)
-        t0 = tel.clock()
-        plan = self.scheduler.schedule()
-        t1 = tel.clock()
-        results = self.runner.execute(plan)
-        if tel.fence:
+            t1 = clock()
+        self._stamp_admissions(plan)
+        results = self._land(self._dispatch(plan), idx)
+        if tel is not None and tel.fence:
             self.runner.sync()
-        t2 = tel.clock()
-        finished += self.scheduler.commit(plan, results)
-        t3 = tel.clock()
-        tel.record_step(plan, timings={"schedule": t1 - t0,
-                                       "execute": t2 - t1,
-                                       "commit": t3 - t2,
-                                       "fenced": tel.fence},
-                        pool=self.scheduler.watermarks())
+        t2 = clock()
+        with _span("serve.commit"):
+            finished += self.scheduler.commit(plan, results)
+        t3 = clock()
+        self.runner.close_exposed()
+        if tel is not None:
+            tel.record_step(plan, timings={"schedule": t1 - t0,
+                                           "execute": t2 - t1,
+                                           "commit": t3 - t2,
+                                           "fenced": tel.fence},
+                            pool=self.scheduler.watermarks())
         return finished
 
     # ------------------------------------------------------------------
@@ -268,25 +290,62 @@ class Engine:
         guarantee is output-invariant. Returns requests finished by the
         step that landed."""
         clock = self._clock()
-        t0 = clock()
-        plan = self.scheduler.schedule()
-        t1 = clock()
-        self._pipe["schedule"] += t1 - t0
+        idx = next(self._step_idx)
+        with _span("serve.schedule", step=idx):
+            t0 = clock()
+            plan = self.scheduler.schedule()
+            t1 = clock()
+        self.stats["host_schedule_s"] += t1 - t0
+        self._stamp_admissions(plan)
         finished = (self._complete_inflight((t0, t1))
                     if self._inflight is not None else [])
         if not (plan.admissions or plan.swap_ins or plan.reclaims
                 or plan.prefill or plan.decode):
+            self.runner.close_exposed()
             return finished            # nothing to dispatch — don't track
-        plan = self.scheduler.resolve_plan(plan)
+        with _span("serve.resolve"):
+            plan = self.scheduler.resolve_plan(plan)
         launch = clock()
-        pending = self.runner.execute_async(plan)
+        pending = self._dispatch(plan)
         s0 = clock()
-        self.scheduler.commit_structural(plan)
+        with _span("serve.commit_structural"):
+            self.scheduler.commit_structural(plan)
         s1 = clock()
-        self._inflight = _Inflight(plan, pending, launch, t1 - t0, s1 - s0)
-        self._pipe["steps"] += 1
+        self._inflight = _Inflight(plan, pending, launch, t1 - t0, s1 - s0,
+                                   idx)
         self.stats["pipelined_steps"] += 1
+        self.runner.close_exposed()
         return finished
+
+    def _stamp_admissions(self, plan: SchedulePlan) -> None:
+        """Count the plan's first admissions (a resume after a preemption
+        was counted at its first) and the seconds each waited since
+        `submit()`."""
+        now = time.perf_counter()
+        for adm in plan.admissions:
+            rid = adm.request.request_id
+            t = self._submitted.pop(rid, None)
+            if t is not None:
+                self.stats["admitted"] += 1
+                self.stats["admit_wait_s"] += now - t
+                self._admitted[rid] = now
+
+    def _dispatch(self, plan: SchedulePlan):
+        """`execute_async(plan)` under its span; counts each request whose
+        first prefill chunk it carries, and the seconds since that
+        request's first admission."""
+        now = time.perf_counter()
+        for ch in plan.prefill:
+            t = self._admitted.pop(ch.request.request_id, None)
+            if t is not None:
+                self.stats["first_chunks"] += 1
+                self.stats["first_chunk_wait_s"] += now - t
+        with _span("serve.dispatch"):
+            return self.runner.execute_async(plan)
+
+    def _land(self, pending, idx: int) -> dict[int, list[int]]:
+        with _span("serve.land", step=idx):
+            return self.runner.wait(pending)
 
     def _complete_inflight(self, overlap_interval: tuple[float, float]
                            | None = None) -> list[FinishedRequest]:
@@ -297,17 +356,18 @@ class Engine:
         [dispatch, wait-end]."""
         inflight = self._inflight
         self._inflight = None
-        results = self.runner.wait(inflight.pending)
+        results = self._land(inflight.pending, inflight.step)
         clock = self._clock()
         t2 = clock()
-        finished = self.scheduler.commit_tokens(inflight.plan, results)
+        with _span("serve.commit"):
+            finished = self.scheduler.commit_tokens(inflight.plan, results)
         t3 = clock()
         execute_s = t2 - inflight.launch_ts
         overlap = 0.0
         if overlap_interval is not None:
             o0, o1 = overlap_interval
             overlap = max(0.0, min(o1, t2) - max(o0, inflight.launch_ts))
-        self._pipe["overlap"] += overlap
+        self.stats["host_overlap_s"] += overlap
         if self.telemetry is not None:
             self.telemetry.record_step(
                 inflight.plan,
@@ -325,17 +385,19 @@ class Engine:
         entry to every synchronous `step()`."""
         if self._inflight is None:
             return []
-        return self._complete_inflight()
+        finished = self._complete_inflight()
+        self.runner.close_exposed()
+        return finished
 
     def overlap_stats(self) -> dict:
         """Aggregate pipelined-overlap accounting: seconds of host
         schedule time total vs hidden under device windows, and the
         resulting overlap fraction (the acceptance metric for the
-        double buffer)."""
-        s = self._pipe
-        frac = (s["overlap"] / s["schedule"]) if s["schedule"] > 0 else 0.0
-        return {"schedule_s": s["schedule"], "overlap_s": s["overlap"],
-                "pipelined_steps": s["steps"], "overlap_frac": frac}
+        double buffer), read from the registry's counters."""
+        sched, over = self.stats["host_schedule_s"], self.stats["host_overlap_s"]
+        return {"schedule_s": sched, "overlap_s": over,
+                "pipelined_steps": self.stats["pipelined_steps"],
+                "overlap_frac": over / sched if sched > 0 else 0.0}
 
     def run_pipelined(self) -> dict[int, np.ndarray]:
         """`run()` over the double-buffered step: drains the queue, all
@@ -421,7 +483,6 @@ class Engine:
         same way — the next `pop_finished_metrics()` only sees requests
         finishing after this call."""
         self.scheduler.reset_stats()
-        self._pipe = {"overlap": 0.0, "schedule": 0.0, "steps": 0}
         if self.telemetry is not None:
             self.telemetry.pop_finished()
 
@@ -452,6 +513,7 @@ class Engine:
         # outlive the request it belonged to; the runner likewise rebuilds
         # its pools from zeros and drops swapped page contents
         self._inflight = None          # lockstep resets drop pending work
+        self._admitted.clear()
         self.scheduler.reset_for_lockstep()
         self.runner.reset_caches()
         if self.scfg.paged:

@@ -41,23 +41,34 @@ and the D2H copy started with ``copy_to_host_async``, but the host only
 blocks for the bytes at the next `wait()`/`sync()` — the transfer rides
 under the same step's decode work.
 
-`execute()` itself splits the same way: `execute_async(plan)` dispatches
+Plan execution splits the same way: `execute_async(plan)` dispatches
 every stage and returns a :class:`_PendingStep` whose decode logits are
 still in flight; `wait(pending)` is the one host sync point, where the
-decode tokens are sampled and pending swap bytes land. A pipelined
-engine schedules plan N+1 between the two; the synchronous `execute()`
-is exactly `wait(execute_async(plan))`.
+decode tokens are sampled and pending swap bytes land (per-slot tokens in
+emission order: a slot completing prefill and decoding in the same step
+yields two). A pipelined engine schedules plan N+1 between the two; the
+synchronous `Engine.step()` calls them back to back.
+
+Sampling is split in three so a profiler trace tells device time from
+host time: the eager slice of the logits rows is dispatched, the host
+blocks on it (span `serve.device_wait`), then fetches the rows and draws
+the tokens (`serve.sample`, counted in `host_sample_s`). From that
+block's return until the next step program has been dispatched (or the
+engine call returns, `close_exposed()`) the device has nothing of this
+engine's to run: those host seconds are `host_exposed_s`.
 """
 from __future__ import annotations
 
 import collections
 import dataclasses
 import functools
+import time
 from typing import Any
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as _span
 
 from repro.core import hamming
 from repro.distributed import sharding as shd
@@ -199,6 +210,9 @@ class ModelRunner:
         # request_ids whose swap-out gathers are still device-side arrays
         # with an async D2H in flight (finalized to numpy at wait()/sync())
         self._pending_swaps: list[int] = []
+        # perf_counter stamp opening the current host-exposed stretch
+        # (None while a program is in flight or outside an engine call)
+        self._exposed_since: float | None = None
 
         if self._tp > 1:
             self._step = self._build_sharded_step()
@@ -315,6 +329,35 @@ class ModelRunner:
         self._finalize_swaps()
         jax.block_until_ready(self.caches)
 
+    def close_exposed(self) -> None:
+        """End the open host-exposed stretch, if any: the call that
+        dispatches the next step program has returned (its argument
+        transfers and launch are host work the device waits on), or the
+        engine call returns."""
+        if self._exposed_since is not None:
+            self.stats["host_exposed_s"] += (time.perf_counter()
+                                             - self._exposed_since)
+            self._exposed_since = None
+
+    def _sample_rows(self, sliced: Array, draws) -> list[int]:
+        """Land one program's sliced logits and draw from them: block
+        until the device has computed them (`serve.device_wait`), then
+        fetch them and draw one token per (row, request, sampling, rng)
+        of `draws` (`serve.sample`). The block's return opens the
+        host-exposed stretch."""
+        with _span("serve.device_wait"):
+            sliced.block_until_ready()
+        t0 = self._exposed_since = time.perf_counter()
+        with _span("serve.sample", slots=len(draws)):
+            rows = np.asarray(sliced).reshape(-1, sliced.shape[-1])
+            toks = []
+            for i, req, sp, rng in draws:
+                if self.logits_sink is not None:
+                    self.logits_sink(req.request_id, rows[i])
+                toks.append(_sample_token(rows[i], sp, rng))
+        self.stats["host_sample_s"] += time.perf_counter() - t0
+        return toks
+
     # ------------------------------------------------------------------
     # low-level steps (shared by plan execution and the lockstep API)
     # ------------------------------------------------------------------
@@ -336,8 +379,10 @@ class ModelRunner:
             jnp.asarray(active), jnp.asarray(n_valid), bt, st,
             n=self.n, binary=self.scfg.binary,
             page_topn=self.scfg.page_topn)
+        self.close_exposed()
         self.stats["prefill_chunks"] += 1
         self.stats["prefill_tokens"] += int(np.asarray(n_valid).sum())
+        self.stats["prefill_rows"] += int(np.size(tokens))
         return logits
 
     def decode_step(self, tokens: np.ndarray, pos: np.ndarray,
@@ -353,6 +398,7 @@ class ModelRunner:
             self.caches, jnp.asarray(pos), jnp.asarray(active), None, bt, st,
             n=self.n, binary=self.scfg.binary,
             page_topn=self.scfg.page_topn)
+        self.close_exposed()
         if self.scfg.paged:
             self._count_decode_traffic(pos, active)
         return logits
@@ -387,12 +433,6 @@ class ModelRunner:
     # ------------------------------------------------------------------
     # plan execution
     # ------------------------------------------------------------------
-    def execute(self, plan: SchedulePlan) -> dict[int, list[int]]:
-        """Run one SchedulePlan verbatim; returns per-slot sampled tokens
-        in emission order (a slot completing prefill and decoding in the
-        same step yields two)."""
-        return self.wait(self.execute_async(plan))
-
     def execute_async(self, plan: SchedulePlan) -> _PendingStep:
         """Dispatch one SchedulePlan without the final host sync: swap
         transfers, state ops, prefill chunks (whose completion samples are
@@ -404,11 +444,14 @@ class ModelRunner:
         N+1."""
         results: dict[int, list[int]] = collections.defaultdict(list)
         for swap_in in plan.swap_ins:               # 1. restores
-            self._swap_in_pages(swap_in.request_id, swap_in.pages,
-                                swap_in.state_page)
+            with _span("serve.swap", pages=len(swap_in.pages)):
+                self._swap_in_pages(swap_in.request_id, swap_in.pages,
+                                    swap_in.state_page)
         for rc in plan.reclaims:                    # 2. gathers
             if rc.kind == "swap-out":
-                self._swap_out_pages(rc.request_id, rc.pages, rc.state_page)
+                with _span("serve.swap", pages=len(rc.pages)):
+                    self._swap_out_pages(rc.request_id, rc.pages,
+                                         rc.state_page)
         for adm in plan.admissions:                 # 3. state entry init
             if adm.state_page < 0 or adm.resume == "swap":
                 continue
@@ -431,28 +474,28 @@ class ModelRunner:
             active[ch.slot] = True
             n_valid = np.zeros((b,), np.int32)
             n_valid[ch.slot] = nv
-            logits = self.prefill_step(
-                tokens,
-                _chunk_extra(req.extra, s, ch.lo, ch.hi, self.chunk,
-                             batch=b, row=ch.slot),
-                np.asarray(ch.pos, np.int32), active, n_valid,
-                plan.block_tables, plan.state_tables)
-            if self.telemetry is not None:
-                self.telemetry.on_chunk(req.request_id)
-            if ch.state_ckpt >= 0:
-                # checkpoint the recurrent state at this chunk's
-                # page-aligned frontier for later prefix restores
-                self._state_copy(int(plan.state_tables[ch.slot]),
-                                 ch.state_ckpt)
-            if ch.samples:
-                row = np.asarray(logits[ch.slot, 0, :vocab])
-                if self.logits_sink is not None:
-                    self.logits_sink(req.request_id, row)
-                tok = _sample_token(row, req.sampling, ch.rng)
-                sampled[ch.slot] = tok
-                results[ch.slot].append(tok)
-                if ch.eos_token is not None and tok == ch.eos_token:
-                    eos_hit.add(ch.slot)
+            with _span("serve.prefill_chunk", request_id=req.request_id,
+                       lo=ch.lo, hi=ch.hi):
+                logits = self.prefill_step(
+                    tokens,
+                    _chunk_extra(req.extra, s, ch.lo, ch.hi, self.chunk,
+                                 batch=b, row=ch.slot),
+                    np.asarray(ch.pos, np.int32), active, n_valid,
+                    plan.block_tables, plan.state_tables)
+                if self.telemetry is not None:
+                    self.telemetry.on_chunk(req.request_id)
+                if ch.state_ckpt >= 0:
+                    # checkpoint the recurrent state at this chunk's
+                    # page-aligned frontier for later prefix restores
+                    self._state_copy(int(plan.state_tables[ch.slot]),
+                                     ch.state_ckpt)
+                if ch.samples:
+                    tok, = self._sample_rows(logits[ch.slot, 0, :vocab],
+                                             [(0, req, req.sampling, ch.rng)])
+                    sampled[ch.slot] = tok
+                    results[ch.slot].append(tok)
+                    if ch.eos_token is not None and tok == ch.eos_token:
+                        eos_hit.add(ch.slot)
         entries = [e for e in plan.decode if e.slot not in eos_hit]
         logits = None
         if entries:                                 # 5. batched decode
@@ -462,9 +505,10 @@ class ModelRunner:
                 tokens[e.slot] = (sampled[e.slot] if e.token is None
                                   else e.token)
                 active[e.slot] = True
-            logits = self.decode_step(
-                tokens, np.asarray(plan.decode_pos, np.int32), active,
-                plan.block_tables, plan.state_tables)
+            with _span("serve.decode", slots=len(entries)):
+                logits = self.decode_step(
+                    tokens, np.asarray(plan.decode_pos, np.int32), active,
+                    plan.block_tables, plan.state_tables)
             self.stats["decode_steps"] += 1
         return _PendingStep(results=dict(results), entries=entries,
                             logits=logits)
@@ -476,12 +520,11 @@ class ModelRunner:
         synchronous path)."""
         self._finalize_swaps()
         if pending.logits is not None:
-            vocab = self.cfg.vocab_size
-            rows = np.asarray(pending.logits[:, 0, :vocab])
-            for e in pending.entries:
-                if self.logits_sink is not None:
-                    self.logits_sink(e.request.request_id, rows[e.slot])
-                tok = _sample_token(rows[e.slot], e.sampling, e.rng)
+            toks = self._sample_rows(
+                pending.logits[:, 0, :self.cfg.vocab_size],
+                [(e.slot, e.request, e.sampling, e.rng)
+                 for e in pending.entries])
+            for e, tok in zip(pending.entries, toks):
                 pending.results.setdefault(e.slot, []).append(tok)
             pending.logits = None
         return pending.results

@@ -296,7 +296,7 @@ class DecodeSlot:
 class SchedulePlan:
     """Everything one engine step executes, decided entirely at plan time.
 
-    Execution order (ModelRunner.execute): swap-in scatters (KV pages +
+    Execution order (ModelRunner.execute_async): swap-in scatters (KV pages +
     state entry), then reclaim gathers (swap-outs, KV + state), then
     admission state-entry init (zero or checkpoint restore), then prefill
     chunks in order (each followed by its planned checkpoint copy), then

@@ -139,6 +139,20 @@ SERVE_COUNTERS: dict[str, str] = {
     "pipelined_steps": "double-buffered steps dispatched before the "
                        "previous step committed",
     "slo_rejected": "submissions refused by SLO-aware admission control",
+    "prefill_rows": "rows the prefill program computed (batch_slots x "
+                    "chunk per chunk, padding included)",
+    "host_schedule_s": "host seconds building plans in pipelined steps",
+    "host_overlap_s": "of host_schedule_s, seconds inside the previous "
+                      "step's device window",
+    "host_exposed_s": "host seconds inside engine calls from a block on "
+                      "logits returning until the next step program is "
+                      "dispatched",
+    "host_sample_s": "host seconds fetching logits rows and drawing tokens",
+    "admitted": "requests admitted into a slot for the first time",
+    "admit_wait_s": "seconds from submit to first admission, summed",
+    "first_chunks": "first prefill chunks dispatched",
+    "first_chunk_wait_s": "seconds from first admission to the dispatch of "
+                          "the first prefill chunk, summed",
 }
 
 
