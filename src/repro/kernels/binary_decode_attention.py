@@ -11,9 +11,11 @@ K/V cache through VMEM in two passes:
   pass 1: scores recomputed (cheap: XOR+popcount), mask = score >= threshold,
           stable exp accumulation of numerator [G, Dv] and denominator [G]
 
-Bytes moved: K cache is uint32 bit-planes (16x smaller than bf16), V is read
-once; scores are never materialized in HBM. The histogram makes top-N a
-streaming O(d)-state operation — no sort, no gather, no O(T) score buffer.
+Bytes moved: K cache is uint32 bit-planes (16x smaller than bf16), read in
+both passes; V's BlockSpec follows the block index in both passes, so V is
+read twice here (the paged kernel reads each page's K and V once); scores
+are never materialized in HBM. The histogram makes top-N a streaming
+O(d)-state operation — no sort, no gather, no O(T) score buffer.
 
 Grid: (B*Hk, 2, T/block_t) — sequential on TPU, so VMEM scratch carries the
 histogram/threshold/accumulators across passes within each (batch, kv-head).
@@ -45,12 +47,13 @@ def _at_or_above(s: Array, valid: Array, d: int) -> Array:
     s, valid: [R, bt]; returns [R, d+1] int32 with column l counting the
     valid keys whose level (s + d) / 2 is >= l. Counts add across blocks,
     and the threshold reads them directly, so no reversed scan is needed.
-    Builds an [R, bt, d+1] compare: for small R * bt (decode blocks).
+    Builds an [R, d+1, bt] compare, keys on lanes, summed across lanes:
+    for small R * bt (decode blocks).
     """
     lv = jnp.where(valid, (s + d) >> 1, -1)                  # [R, bt]
-    ge = lv[:, :, None] >= jax.lax.broadcasted_iota(jnp.int32,
-                                                    (1, 1, d + 1), 2)
-    return jnp.sum(ge.astype(jnp.int32), axis=1)
+    ge = lv[:, None, :] >= jax.lax.broadcasted_iota(jnp.int32,
+                                                    (1, d + 1, 1), 1)
+    return jnp.sum(ge.astype(jnp.int32), axis=2)
 
 
 def _threshold(cc: Array, nsel: Array, d: int) -> Array:

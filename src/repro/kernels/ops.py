@@ -179,6 +179,13 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
     lengths. Returns [B, H, Dv] f32. Block tables and lengths are
     traced: new contents never recompile.
 
+    The kernel walks each (slot, kv-head) row in blocks of
+    `pages_per_block(page)` listed pages (32 at page 16, ~512 tokens a
+    grid step) and stops after the row's last resident page, so a
+    decode step costs what the rows hold, not `max_blocks`. Each page's
+    K is read once, in the histogram pass (the second pass reuses its
+    scores), and its V once, in the second.
+
     page_topn (STATIC) switches on two-phase page-sparse decode:
     phase 1 scores every resident page per (slot, kv-head) with the
     popcount upper-bound kernel, phase 2 runs the decode kernel over a
@@ -186,7 +193,8 @@ def paged_decode_attention(q_bits: Array, k_pool: Array, v_pool: Array,
     always included), so V gathers drop from O(context) to
     O(page_topn * page). At page_topn >= max_blocks the dense walk runs
     unchanged; at page_topn >= resident pages the result is
-    bit-identical to dense (all resident pages selected, same order).
+    bit-identical to dense (all resident pages selected, same order,
+    grouped into the same blocks).
 
     Head-shardable by construction: every row of the flattened
     (slot, kv-head) grid — scoring, `select_pages` compaction, and the

@@ -417,10 +417,22 @@ class ModelRunner:
         dense reads every resident page's K and V; page-sparse phase 1
         reads every resident page's k_bits and phase 2 reads only the
         min(page_topn, resident) selected pages' k_bits + V.
+
+        `decode_pages_listed` counts the block-table pages the decode
+        kernel is handed (max_blocks per slot, or min(page_topn,
+        max_blocks) per compacted row), `decode_pages_walked` those it
+        walks: the ceil((pos+1)/page) resident ones, for every slot,
+        active or not, as the kernel sees length pos + 1 for each.
         """
-        res = (np.asarray(pos, np.int64)[np.asarray(active, bool)]
-               + self.page) // self.page          # ceil((pos+1)/page)
         ptn = self.scfg.page_topn
+        nb = pages_needed(self.scfg.max_len, self.page)
+        listed = nb if ptn is None else min(ptn, nb)
+        pos = np.asarray(pos, np.int64)
+        walked = np.minimum((pos + self.page) // self.page, listed)
+        self.stats["decode_pages_walked"] += int(walked.sum())
+        self.stats["decode_pages_listed"] += listed * walked.size
+        res = (pos[np.asarray(active, bool)]
+               + self.page) // self.page          # ceil((pos+1)/page)
         sel = res if ptn is None else np.minimum(res, ptn)
         self.stats["decode_pages_touched"] += int(sel.sum())
         kb, vb = self._page_k_bytes, self._page_v_bytes

@@ -141,6 +141,10 @@ SERVE_COUNTERS: dict[str, str] = {
     "slo_rejected": "submissions refused by SLO-aware admission control",
     "prefill_rows": "rows the prefill program computed (batch_slots x "
                     "chunk per chunk, padding included)",
+    "decode_pages_walked": "block-table pages with tokens that paged "
+                           "decode steps walked, summed over slots",
+    "decode_pages_listed": "block-table pages paged decode steps listed "
+                           "(table width x slots), resident or not",
     "host_schedule_s": "host seconds building plans in pipelined steps",
     "host_overlap_s": "of host_schedule_s, seconds inside the previous "
                       "step's device window",

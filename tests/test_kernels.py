@@ -13,6 +13,7 @@ import pytest
 from _hypothesis_compat import given, settings, st
 
 from repro.core import hamming
+from repro.kernels import binary_paged_decode_attention as PDEC
 from repro.kernels import ops, ref
 
 
@@ -294,45 +295,49 @@ def test_decode_agrees_with_prefill_last_row():
 # ---------------------------------------------------------------------------
 
 def _paged_case(b, h, hk, nb, page, d, dv, nsel, lengths, n_pages,
-                seed=0, vdtype=jnp.float32):
+                seed=0, vdtype=jnp.float32, nan_fill=False):
     """Scatter contiguous K/V into a shuffled page pool, then check the
     paged kernel against (a) the gather-based oracle and (b) the
     contiguous kernel on the same tokens — the latter bit-exactly, since
-    pages stream in logical order with block_t == page."""
-    t = nb * page
-    rng = np.random.default_rng(seed + 2)
-    qb = _bits((b, h, d), seed)
-    kb = _bits((b, hk, t, d), seed + 1)            # row-major contiguous
-    v = jnp.asarray(rng.normal(size=(b, hk, t, dv)).astype(np.float32),
-                    dtype=vdtype)
-    w = kb.shape[-1]
-    perm = rng.permutation(n_pages)[: b * nb]
-    bt = perm.reshape(b, nb).astype(np.int32)
-    k_pool = np.zeros((n_pages, hk, w, page), np.uint32)
-    v_pool = np.zeros((n_pages, hk, page, dv),
-                      np.asarray(jnp.zeros((), vdtype)).dtype)
-    for bi in range(b):
-        for j in range(nb):
-            pg = bt[bi, j]
-            k_pool[pg] = np.swapaxes(
-                np.asarray(kb)[bi, :, j * page:(j + 1) * page], -1, -2)
-            v_pool[pg] = np.asarray(v)[bi, :, j * page:(j + 1) * page]
+    pages stream in logical order and the contiguous kernel's block_t is
+    the paged kernel's block of pages_per_block(page) pages.
+
+    nan_fill: every pool page outside all rows' residency gets NaN V and
+    all-ones K bits; the kernel must give the same bits as on the clean
+    pool, so pages it does not fetch, or holds stale, contribute nothing.
+    """
+    qb, kb, v, k_pool, v_pool, bt = _make_pool(b, h, hk, nb, page, d, dv,
+                                               n_pages, seed, vdtype)
     scale = 1.0 / np.sqrt(d)
     lengths = jnp.asarray(lengths, jnp.int32)
     got = ops.paged_decode_attention(
-        qb, jnp.asarray(k_pool), jnp.asarray(v_pool), jnp.asarray(bt),
-        d=d, nsel=nsel, scale=scale, lengths=lengths, interpret=True)
+        qb, k_pool, v_pool, bt, d=d, nsel=nsel, scale=scale,
+        lengths=lengths, interpret=True)
     g = h // hk
     want = ref.paged_decode_attention_ref(
-        qb.reshape(b, hk, g, -1), jnp.asarray(k_pool), jnp.asarray(v_pool),
-        jnp.asarray(bt), d=d, nsel=nsel, scale=scale,
-        lengths=lengths).reshape(b, h, dv)
+        qb.reshape(b, hk, g, -1), k_pool, v_pool, bt, d=d, nsel=nsel,
+        scale=scale, lengths=lengths).reshape(b, h, dv)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want, np.float32),
                                rtol=2e-5, atol=2e-5)
-    contig = ops.decode_attention(qb, kb, v, d=d, nsel=nsel, scale=scale,
-                                  lengths=lengths, block_t=page,
-                                  interpret=True)
+    block_t = PDEC.pages_per_block(page) * page
+    pad = [(0, 0), (0, 0), (0, -(nb * page) % block_t), (0, 0)]
+    contig = ops.decode_attention(qb, jnp.pad(kb, pad), jnp.pad(v, pad), d=d,
+                                  nsel=nsel, scale=scale, lengths=lengths,
+                                  block_t=block_t, interpret=True)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(contig))
+    if nan_fill:
+        resident = np.zeros(n_pages, bool)
+        for bi, n in enumerate(np.asarray(lengths)):
+            resident[np.asarray(bt)[bi, :-(-int(n) // page)]] = True
+        assert not resident.all(), "no page outside residency: test is void"
+        k_nan = np.asarray(k_pool).copy()
+        v_nan = np.asarray(v_pool).copy()
+        k_nan[~resident] = np.uint32(0xFFFFFFFF)
+        v_nan[~resident] = np.nan
+        dirty = ops.paged_decode_attention(
+            qb, jnp.asarray(k_nan), jnp.asarray(v_nan), bt, d=d, nsel=nsel,
+            scale=scale, lengths=lengths, interpret=True)
+        np.testing.assert_array_equal(np.asarray(dirty), np.asarray(got))
 
 
 @pytest.mark.parametrize("d", [32, 64, 128])
@@ -347,6 +352,19 @@ def test_paged_decode_ragged_lengths_and_garbage_tail():
     clamps them and `lengths` masks whatever page they alias."""
     _paged_case(b=3, h=2, hk=1, nb=8, page=8, d=32, dv=8, nsel=5,
                 lengths=[64, 17, 1], n_pages=24, seed=7)
+
+
+@pytest.mark.parametrize("nan_fill", [False, True], ids=["clean", "nan"])
+@pytest.mark.parametrize("lengths", [
+    [512, 517, 1, 0],   # a block boundary, mid-page in block 2, 1, empty
+    [640, 496, 33, 16],  # the full table, mid-block, mid-page, one page
+], ids=["edges", "ragged"])
+def test_paged_decode_blocked_walk(lengths, nan_fill):
+    """Blocks of 32 pages at page 16 over a 40-page table (not a whole
+    number of blocks): the walk ends at each row's last resident page."""
+    _paged_case(b=4, h=4, hk=2, nb=40, page=16, d=32, dv=8, nsel=40,
+                lengths=lengths, n_pages=4 * 40 + 6, seed=21,
+                nan_fill=nan_fill)
 
 
 def test_paged_decode_bf16_values():
@@ -395,8 +413,8 @@ def test_decode_block_skip_matches_no_skip():
 
 def _make_pool(b, h, hk, nb, page, d, dv, n_pages, seed=0,
                vdtype=jnp.float32):
-    """Contiguous K/V scattered into a shuffled page pool (as _paged_case),
-    returned with the contiguous originals for oracle calls."""
+    """Contiguous K/V scattered into a shuffled page pool, returned with
+    the contiguous originals for oracle calls."""
     t = nb * page
     rng = np.random.default_rng(seed + 2)
     qb = _bits((b, h, d), seed)

@@ -183,3 +183,30 @@ def test_overlap_stats_read_the_registry(params):
     eng.reset_stats()
     assert eng.overlap_stats() == {"schedule_s": 0, "overlap_s": 0,
                                    "pipelined_steps": 0, "overlap_frac": 0.0}
+
+
+@pytest.mark.parametrize("page_topn", [None, 2])
+def test_decode_page_counters_follow_residency(params, page_topn):
+    """Each decode step lists every slot's table row, max_len / page = 12
+    pages (page_topn of them when compacted), and walks the
+    ceil((pos + 1) / page) that hold tokens, empty slots included."""
+    eng = Engine(CFG, params, _scfg(paged=True, page_size=4,
+                                    page_topn=page_topn))
+    seen = []
+    decode_step = eng.runner.decode_step
+
+    def spy(tokens, pos, *args, **kw):
+        seen.append(np.array(pos))
+        return decode_step(tokens, pos, *args, **kw)
+
+    eng.runner.decode_step = spy
+    for n, gen in ((5, 3), (21, 6)):
+        eng.submit(np.arange(1, n + 1, dtype=np.int32), max_new_tokens=gen)
+    eng.run()
+    listed = 12 if page_topn is None else page_topn
+    st = eng.stats
+    assert len(seen) == st["decode_steps"] > 0
+    assert st["decode_pages_listed"] == listed * 2 * len(seen)
+    assert st["decode_pages_walked"] == sum(
+        np.minimum(-(-(p + 1) // 4), listed).sum() for p in seen)
+    assert 0 < st["decode_pages_walked"] < st["decode_pages_listed"]

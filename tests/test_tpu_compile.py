@@ -7,7 +7,10 @@ Widths: smollm-135m (head_dim 64 -> 2 packed words, 3 KV heads, group 3)
 and granite-3-8b (head_dim 128 -> 4 words, 8 KV heads, group 4), served
 with 8 slots, max_len 2048, page 16, prefill chunk 512; the paged decode
 kernel also at max_len 32768, where its per-block skip list and block
-tables are 16x longer.
+tables are 16x longer, and at the benchmark cells' shapes:
+granite8b.longctx_decode (granite widths, 8 slots, max_len 20480) and
+phi3m.chat (phi-3-medium: head_dim 128, 10 KV heads, group 4; 16 slots,
+max_len 3072).
 """
 from __future__ import annotations
 
@@ -24,6 +27,7 @@ WIDTHS = {  # arch -> (head_dim, n_kv_heads, group)
     "smollm-135m": (64, 3, 3),
     "granite-3-8b": (128, 8, 4),
 }
+PHI3M = (128, 10, 4)
 SLOTS, MAX_LEN, PAGE, CHUNK = 8, 2048, 16, 512
 LONG_LEN = 32768
 
@@ -53,10 +57,10 @@ def _compile(fn, *args):
     assert "tpu_custom_call" in text     # the Mosaic kernel, not a fallback
 
 
-def _shapes(one_chip, arch, max_len=MAX_LEN):
+def _shapes(one_chip, arch):
     dh, hk, g = WIDTHS[arch]
     w = hamming.packed_words(dh)
-    nb = max_len // PAGE
+    nb = MAX_LEN // PAGE
 
     def s(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -64,24 +68,39 @@ def _shapes(one_chip, arch, max_len=MAX_LEN):
     return dh, hk, g, w, nb, s
 
 
-@pytest.mark.parametrize("max_len", [MAX_LEN, LONG_LEN])
-@pytest.mark.parametrize("page_topn", [None, 8], ids=["dense", "top8"])
-@pytest.mark.parametrize("arch", list(WIDTHS))
-def test_paged_decode_compiles(one_chip, arch, page_topn, max_len):
+PAGED_CASES = [  # (widths, page_topn, slots, max_len), id
+    *(pytest.param(WIDTHS[arch], ptn, SLOTS, max_len,
+                   id=f"{arch}-{ptn_id}-{max_len}")
+      for arch in WIDTHS for ptn, ptn_id in ((None, "dense"), (8, "top8"))
+      for max_len in (MAX_LEN, LONG_LEN)),
+    pytest.param(WIDTHS["granite-3-8b"], None, 8, 20480,
+                 id="granite8b.longctx_decode"),
+    pytest.param(PHI3M, None, 16, 3072, id="phi3m.chat"),
+]
+
+
+@pytest.mark.parametrize("widths,page_topn,slots,max_len", PAGED_CASES)
+def test_paged_decode_compiles(one_chip, widths, page_topn, slots,
+                               max_len):
     """Dense paged decode, and with page_topn the page-score kernel
     followed by decode over the compacted block tables."""
-    dh, hk, g, w, nb, s = _shapes(one_chip, arch, max_len)
-    n_pages = SLOTS * nb
+    dh, hk, g = widths
+    w = hamming.packed_words(dh)
+    nb = max_len // PAGE
+    n_pages = slots * nb
+
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     def step(q, kp, vp, bt, lengths, scale):
         return ops.paged_decode_attention(
             q, kp, vp, bt, d=dh, nsel=240, scale=scale, lengths=lengths,
             page_topn=page_topn, interpret=False)
 
-    _compile(step, s((SLOTS, hk * g, w), jnp.uint32),
+    _compile(step, s((slots, hk * g, w), jnp.uint32),
              s((n_pages, hk, w, PAGE), jnp.uint32),
              s((n_pages, hk, PAGE, dh), jnp.bfloat16),
-             s((SLOTS, nb), jnp.int32), s((SLOTS,), jnp.int32),
+             s((slots, nb), jnp.int32), s((slots,), jnp.int32),
              s((), jnp.float32))
 
 
